@@ -69,6 +69,7 @@ def run_backend(name: str, quick: bool = False,
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
     from repro.index import available
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
@@ -83,6 +84,7 @@ def main() -> None:
                     help="batched-search chunk for the --backend run "
                          "(unset = capacity-derived default, 0 = unchunked)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.backend:
         print("name,us_per_call,derived")

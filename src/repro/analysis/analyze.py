@@ -6,7 +6,7 @@ F15x, cross-program/recompilation checks are F16x — see
 tools/foldprog/RULES.md):
 
   F151  float64/complex leak — the jaxpr is re-traced under x64 semantics
-        (`jax.experimental.enable_x64`); any f64/c128 aval means the code
+        (`jax.enable_x64(True)`); any f64/c128 aval means the code
         relies on JAX's 32-bit canonicalization instead of dtype
         discipline, and would silently double its FLOPs/bytes under an
         x64-enabled host. (int64 from index-producing primitives like
@@ -135,7 +135,7 @@ def lower_compile(jit_fn, *args, **kwargs) -> CompiledMeasure:
 def _iter_eqns(jaxpr):
     """Yield every eqn in a jaxpr, recursing into sub-jaxprs (pjit bodies,
     while cond/body, scan/cond branches, vmap-of-closed-call, ...)."""
-    from jax import core as jcore
+    from jax.extend import core as jcore
     stack = [jaxpr]
     while stack:
         j = stack.pop()
@@ -205,8 +205,7 @@ def _count_donated(lowered) -> int:
 # ---------------------------------------------------------------- checks
 def _check_dtypes(spec: ProgramSpec, jit_fn, args, kwargs):
     """F151/F152: re-trace under x64 semantics and audit avals."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = _trace(jit_fn, args, kwargs)
     f64 = sorted({
         _aval_str(v.aval)

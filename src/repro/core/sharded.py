@@ -38,18 +38,27 @@ from repro.core.hnsw import (HNSWConfig, HNSWState, hnsw_compact, hnsw_delete,
 from repro.index.pipeline import greedy_leader
 from repro.kernels import ref as kref
 
-__all__ = ["sharded_init", "make_sharded_dedup_step", "sharded_state_specs",
-           "sharded_grow", "make_sharded_delete", "make_sharded_compact",
-           "make_sharded_search"]
+__all__ = ["sharded_init", "sharded_init_program", "make_sharded_dedup_step",
+           "sharded_state_specs", "sharded_grow", "make_sharded_delete",
+           "make_sharded_compact", "make_sharded_search"]
 
 
 def sharded_init(cfg: HNSWConfig, mesh: Mesh, axis: str = "data") -> HNSWState:
     """Stacked per-shard states with a leading device axis (sharded)."""
+    return sharded_init_program(cfg, mesh, axis)()
+
+
+def sharded_init_program(cfg: HNSWConfig, mesh: Mesh, axis: str = "data"):
+    """The argument-free program `sharded_init` runs.
+
+    It is sharded on its output, so each device fills only its own shard:
+    the stacked state (N x one chip's index) never has to fit on a single
+    device."""
     n = mesh.shape[axis]
-    one = hnsw_init(cfg)
-    stacked = jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape), one)
-    specs = sharded_state_specs(mesh, axis)
-    return jax.tree.map(lambda x, s: jax.device_put(x, s), stacked, specs)
+    return jax.jit(
+        lambda: jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape),
+                             hnsw_init(cfg)),
+        out_shardings=sharded_state_specs(mesh, axis))
 
 
 def sharded_state_specs(mesh: Mesh, axis: str = "data"):
@@ -94,11 +103,8 @@ def sharded_grow(cfg: HNSWConfig, states: HNSWState, new_capacity: int,
 
 
 def _smap(mesh: Mesh):
-    """shard_map constructor across JAX versions (see make_sharded_dedup_step)."""
-    if hasattr(jax, "shard_map"):
-        return functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return functools.partial(_shard_map, mesh=mesh, check_rep=False)
+    """shard_map over `mesh` with the replication check off."""
+    return functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
 
 
 def _state_specs_p(axis: str):
@@ -269,17 +275,10 @@ def make_sharded_dedup_step(cfg: HNSWConfig, mesh: Mesh, *, tau: float,
             return state, keep, keep_in
         return state, keep
 
-    # jax.shard_map only exists from 0.6; fall back to the experimental
-    # location (0.4.x) where the replication-check kwarg is `check_rep`.
-    if hasattr(jax, "shard_map"):
-        smap = functools.partial(jax.shard_map, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        smap = functools.partial(_shard_map, check_rep=False)
     n_in = (5 if masked else 4) + (1 if free_slots else 0)
     out_keep = (P(), P()) if masked else (P(),)
-    step = smap(
-        local, mesh=mesh,
+    step = _smap(mesh)(
+        local,
         in_specs=(HNSWState(*(P(axis),) * len(HNSWState._fields)),)
         + (P(axis),) * (n_in - 1),
         out_specs=(HNSWState(*(P(axis),) * len(HNSWState._fields)),)

@@ -85,10 +85,14 @@ class BruteForceBackend(DedupBackend):
         ids = np.full((B, 1), -1, np.int32)
         sims = np.full((B, 1), -np.inf, np.float32)
         if self.n > 0:
-            db = jnp.asarray(self.store[: self.n])
+            # whole chunks only (rows past n score as free), so the scan
+            # compiles once instead of once per distinct occupancy
+            n_pad = min(-(-self.n // _CHUNK) * _CHUNK, self.capacity)
+            db = jnp.asarray(self.store[:n_pad])
             for s in range(0, self.n, _CHUNK):
-                fm = self._free_mask[s:s + min(_CHUNK, self.n - s)]
-                j_dev, best_dev = _chunk_best(sig.sigs, db[s:s + _CHUNK],
+                e = min(s + _CHUNK, n_pad)
+                fm = self._free_mask[s:e] | (np.arange(s, e) >= self.n)
+                j_dev, best_dev = _chunk_best(sig.sigs, db[s:e],
                                               jnp.asarray(fm))
                 # the per-chunk running max lives on host; two (B,)
                 # vectors is the whole transfer

@@ -6,12 +6,15 @@ single largest stage in the paper's breakdown (Fig. 7: ~48 s per 100K docs),
 so it earns a kernel.
 
 Tiling: grid (B/TB, H/TH, L/TL) with the shingle dim innermost so the output
-tile acts as a VMEM accumulator: at l==0 it is initialized to UINT32_MAX and
-every l-step folds a (TB, TH, TL) hashed block into a running minimum.
+tile acts as a VMEM accumulator: at l==0 it is initialized to the largest key
+and every l-step folds a (TB, TH, TL) hashed block into a running minimum.
 TB=8, TH=128, TL=128 -> the hashed intermediate is (8,128,128) u32 = 512 KiB.
 
-Note on dtypes: the min-reduction must be *unsigned*; Mosaic handles uint32
-min natively, and interpret mode matches numpy semantics.
+Note on dtypes: the min-reduction must be *unsigned*, but Mosaic implements
+no reduction over unsigned integers. The kernel therefore reduces order-
+preserving int32 keys (the hash with its sign bit flipped, bitcast): signed
+order on the keys is unsigned order on the hashes, so the result is
+bit-identical once the wrapper flips the bit back.
 """
 from __future__ import annotations
 
@@ -30,6 +33,8 @@ TL = 128  # shingles per tile
 
 # numpy scalars (not jnp) so the kernel body does not capture traced consts.
 UINT32_MAX = np.uint32(0xFFFFFFFF)
+_SIGN = np.uint32(0x80000000)
+_KEY_MAX = np.int32(0x7FFFFFFF)   # the key of UINT32_MAX
 _GOLDEN = np.uint32(0x9E3779B9)
 _C1 = np.uint32(0x85EBCA6B)
 _C2 = np.uint32(0xC2B2AE35)
@@ -49,7 +54,7 @@ def _minhash_kernel(sh_ref, seed_ref, out_ref):
 
     @pl.when(l_idx == 0)
     def _init():
-        out_ref[...] = jnp.full_like(out_ref, UINT32_MAX)
+        out_ref[...] = jnp.full_like(out_ref, _KEY_MAX)
 
     sh = sh_ref[...]                    # (TB, TL) uint32
     seeds = seed_ref[...]               # (TH, 1)  uint32
@@ -58,7 +63,8 @@ def _minhash_kernel(sh_ref, seed_ref, out_ref):
     expanded = sh[:, None, :] ^ seeds.reshape(1, -1, 1)
     hashed = _fmix32(expanded * _GOLDEN + seeds.reshape(1, -1, 1))
     hashed = jnp.where(valid[:, None, :], hashed, UINT32_MAX)
-    tile_min = jnp.min(hashed, axis=-1)  # (TB, TH)
+    keys = jax.lax.bitcast_convert_type(hashed ^ _SIGN, jnp.int32)
+    tile_min = jnp.min(keys, axis=-1)    # (TB, TH)
     out_ref[...] = jnp.minimum(out_ref[...], tile_min)
 
 
@@ -90,7 +96,7 @@ def minhash_kernel_signatures(shingles: jnp.ndarray, seeds: jnp.ndarray, *,
         in_specs=[pl.BlockSpec((TB, TL), lambda b, h, l: (b, l)),
                   pl.BlockSpec((TH, 1), lambda b, h, l: (h, 0))],
         out_specs=pl.BlockSpec((TB, TH), lambda b, h, l: (b, h)),
-        out_shape=jax.ShapeDtypeStruct((Bp, Hp), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((Bp, Hp), jnp.int32),
         interpret=interpret,
     )(sh_p, seeds_p[:, None])
-    return out[:B, :H]
+    return jax.lax.bitcast_convert_type(out[:B, :H], jnp.uint32) ^ _SIGN

@@ -1,14 +1,13 @@
 """Public jit'd entry points for the Pallas kernels.
 
 `use_kernel` selects the Pallas path ('SIMD' in the paper's ablation, Fig. 8)
-vs the pure-jnp oracle; `interpret` runs the kernel body in Python on CPU.
-On this CPU container the default is interpret=True; on a real TPU runtime
-set REPRO_PALLAS_INTERPRET=0 (or pass interpret=False) for compiled Mosaic.
+vs the pure-jnp oracle; `interpret` runs the kernel body in Python. Left
+unset, it follows the backend the arrays live on: interpreted on CPU,
+compiled Mosaic everywhere else.
 """
 from __future__ import annotations
 
-import os
-
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
@@ -18,8 +17,16 @@ from repro.kernels.minhash import minhash_kernel_signatures
 __all__ = ["bitmap_jaccard", "hamming", "minhash", "default_interpret"]
 
 
-def default_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def default_interpret(x=None) -> bool:
+    """True iff a kernel over `x` must run interpreted, i.e. on CPU.
+
+    A concrete device array answers with its own platform; host arrays and
+    tracers run on the default backend."""
+    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+        platform = next(iter(x.devices())).platform
+    else:
+        platform = jax.default_backend()
+    return platform == "cpu"
 
 
 def bitmap_jaccard(qs: jnp.ndarray, db: jnp.ndarray,
@@ -32,7 +39,7 @@ def bitmap_jaccard(qs: jnp.ndarray, db: jnp.ndarray,
         if not cached:
             pq = pb = None  # force on-the-fly popcounts (ablation arm)
         return ref.bitmap_jaccard_ref(qs, db, pq, pb)
-    itp = default_interpret() if interpret is None else interpret
+    itp = default_interpret(qs) if interpret is None else interpret
     return bitmap_jaccard_matrix(qs, db, pq, pb, cached=cached, interpret=itp)
 
 
@@ -40,7 +47,7 @@ def hamming(qs: jnp.ndarray, db: jnp.ndarray, *, use_kernel: bool = True,
             interpret: bool | None = None) -> jnp.ndarray:
     if not use_kernel:
         return ref.hamming_ref(qs, db)
-    itp = default_interpret() if interpret is None else interpret
+    itp = default_interpret(qs) if interpret is None else interpret
     return hamming_matrix(qs, db, interpret=itp)
 
 
@@ -48,5 +55,5 @@ def minhash(shingles: jnp.ndarray, seeds: jnp.ndarray, *,
             use_kernel: bool = True, interpret: bool | None = None) -> jnp.ndarray:
     if not use_kernel:
         return ref.minhash_ref(shingles, seeds)
-    itp = default_interpret() if interpret is None else interpret
+    itp = default_interpret(shingles) if interpret is None else interpret
     return minhash_kernel_signatures(shingles, seeds, interpret=itp)

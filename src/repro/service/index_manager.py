@@ -132,10 +132,11 @@ class IndexManager:
         self.pipe.save(self.snapshot_dir, self._snap_step,
                        async_write=not sync)
         self.snapshots_taken += 1
-        # rotate committed steps; an in-flight async write is not listed
-        # yet, so keep one fewer committed step to land on max_snapshots
-        keep = self.max_snapshots - (0 if sync else 1)
-        steps = ckpt.list_steps(self.snapshot_dir)
+        # rotate the older committed steps around the current one, which an
+        # async write may or may not have committed by now
+        keep = self.max_snapshots - 1
+        steps = [s for s in ckpt.list_steps(self.snapshot_dir)
+                 if s != self._snap_step]
         for old in (steps[:-keep] if keep > 0 else steps):
             shutil.rmtree(os.path.join(self.snapshot_dir,
                                        f"step_{old:08d}"))

@@ -3,8 +3,7 @@ import sys
 
 # Tests run on the single real CPU device (the 512-device override is ONLY
 # for launch/dryrun.py, which sets it before importing jax in its own
-# process). Keep pallas kernels in interpret mode here.
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+# process); Pallas kernels run interpreted there because the backend is CPU.
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

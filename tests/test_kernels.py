@@ -69,3 +69,18 @@ def test_kernel_vs_jnp_paths_agree():
     a = ops.bitmap_jaccard(qs, db, use_kernel=True, interpret=True)
     b = ops.bitmap_jaccard(qs, db, use_kernel=False)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+
+
+def test_interpret_mode_follows_cpu_backend():
+    """Unset `interpret` resolves from the arrays' backend: on CPU the
+    kernels must run interpreted (Mosaic only compiles for a TPU)."""
+    import jax
+    assert jax.default_backend() == "cpu"
+    assert ops.default_interpret() is True
+    assert ops.default_interpret(jnp.zeros((8, 128), jnp.uint32)) is True
+    assert ops.default_interpret(np.zeros((8, 128), np.uint32)) is True
+    # and the default path still matches the oracle bit for bit
+    sh = jnp.asarray(RNG.integers(0, 2**32, (8, 128), dtype=np.uint32))
+    seeds = hash_seeds(16)
+    np.testing.assert_array_equal(np.asarray(ops.minhash(sh, seeds)),
+                                  np.asarray(ref.minhash_ref(sh, seeds)))

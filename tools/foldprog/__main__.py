@@ -11,8 +11,8 @@ Subcommands:
 
 Also runnable as `python tools/foldprog ...` — the bootstrap below puts
 tools/ (for the package) and src/ (for repro) on sys.path, and pins the
-analysis environment (CPU, interpreted Pallas) BEFORE jax is imported so
-golden fingerprints are host-independent.
+analysis environment (CPU, hence interpreted Pallas) BEFORE jax is
+imported so golden fingerprints are host-independent.
 """
 from __future__ import annotations
 
@@ -28,7 +28,6 @@ if __package__ in (None, ""):                      # python tools/foldprog
 # pin the lowering environment before any jax import: fingerprints must not
 # depend on which accelerator the developer's machine happens to have
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 try:
     import repro  # noqa: F401  (src/ already on the caller's PYTHONPATH?)
