@@ -1,0 +1,6 @@
+"""Device ms per micro-batch in the batched HNSW insert."""
+from harness.reduce import Context, module_ms_per_batch
+
+
+def read(ctx: Context) -> float | None:
+    return module_ms_per_batch(ctx, ("hnsw_insert_batch",))
