@@ -466,17 +466,19 @@ def hnsw_search(cfg: HNSWConfig, state: HNSWState, queries: jnp.ndarray,
     qpcs = jnp.sum(jax.lax.population_count(queries).astype(jnp.int32), -1)
 
     def one(q, qpc):
-        visited = _visited_new(cfg)
-        cur, curd = _descend(cfg, state, q, qpc, jnp.int32(0))
-        ids, d, _ = _search_layer(cfg, state, q, qpc, 0, ef,
-                                  cur[None], curd[None], visited)
-        # tombstoned nodes stay navigable inside the beam (connectivity)
-        # but are masked out of the returned top-k
-        ids, d = _mask_dead_sorted(state, ids, d)
-        ids, d = ids[:k], d[:k]
-        empty = state.count == 0
-        ids = jnp.where(empty | (ids < 0) | ~jnp.isfinite(d), -1, ids)
-        sims = jnp.where(ids >= 0, 1.0 - d, -jnp.inf)
+        with jax.named_scope("fold.search.descend"):
+            cur, curd = _descend(cfg, state, q, qpc, jnp.int32(0))
+        with jax.named_scope("fold.search.beam"):
+            visited = _visited_new(cfg)
+            ids, d, _ = _search_layer(cfg, state, q, qpc, 0, ef,
+                                      cur[None], curd[None], visited)
+            # tombstoned nodes stay navigable inside the beam (connectivity)
+            # but are masked out of the returned top-k
+            ids, d = _mask_dead_sorted(state, ids, d)
+            ids, d = ids[:k], d[:k]
+            empty = state.count == 0
+            ids = jnp.where(empty | (ids < 0) | ~jnp.isfinite(d), -1, ids)
+            sims = jnp.where(ids >= 0, 1.0 - d, -jnp.inf)
         return ids, sims
 
     return _chunked_map(jax.vmap(one), (queries, qpcs), query_chunk)
@@ -490,26 +492,28 @@ def _select_diverse(cfg, state, cand_ids, cand_d, m_l: int):
     cand_ids/cand_d: (E,) sorted ascending, -1/-inf padded. Returns (E,)
     ids with non-selected slots set to -1 (selected count <= m_l).
     """
-    E = cand_ids.shape[0]
-    safe = jnp.maximum(cand_ids, 0)
-    vecs = state.vectors[safe]
-    pcs = state.pb[safe]
-    # pairwise candidate-candidate distances (E x E); rows for invalid ids
-    # are never consulted (their selection is masked out below)
-    cc = jax.vmap(lambda v, p: _dist_rows(cfg, v, p, vecs, pcs))(vecs, pcs)
+    with jax.named_scope("fold.select_diverse"):
+        E = cand_ids.shape[0]
+        safe = jnp.maximum(cand_ids, 0)
+        vecs = state.vectors[safe]
+        pcs = state.pb[safe]
+        # pairwise candidate-candidate distances (E x E); rows for invalid
+        # ids are never consulted (their selection is masked out below)
+        cc = jax.vmap(lambda v, p: _dist_rows(cfg, v, p, vecs, pcs))(vecs,
+                                                                      pcs)
 
-    def body(i, carry):
-        selected, count = carry
-        cand_ok = (cand_ids[i] >= 0) & (count < m_l)
-        # distance to the closest already-selected neighbor
-        dsel = jnp.min(jnp.where(selected, cc[i], jnp.inf))
-        diverse = cand_d[i] < dsel
-        take = cand_ok & diverse
-        return selected.at[i].set(take), count + take.astype(jnp.int32)
+        def body(i, carry):
+            selected, count = carry
+            cand_ok = (cand_ids[i] >= 0) & (count < m_l)
+            # distance to the closest already-selected neighbor
+            dsel = jnp.min(jnp.where(selected, cc[i], jnp.inf))
+            diverse = cand_d[i] < dsel
+            take = cand_ok & diverse
+            return selected.at[i].set(take), count + take.astype(jnp.int32)
 
-    selected, _ = jax.lax.fori_loop(
-        0, E, body, (jnp.zeros((E,), jnp.bool_), jnp.int32(0)))
-    return jnp.where(selected, cand_ids, -1)
+        selected, _ = jax.lax.fori_loop(
+            0, E, body, (jnp.zeros((E,), jnp.bool_), jnp.int32(0)))
+        return jnp.where(selected, cand_ids, -1)
 
 
 def _prune_row(cfg, state, node, level: int, cand_ids, cand_d, m_l: int):
@@ -887,26 +891,28 @@ def hnsw_insert_batch(cfg: HNSWConfig, state: HNSWState, vecs: jnp.ndarray,
     # phase A runs against the pre-batch graph (reads only graph-reachable
     # rows — never a reclaimed slot — so the bulk slot write below cannot
     # alias it)
-    cand_ids, cand_d = _discover_candidates(cfg, state, vecs, pcs, levels,
-                                            seed_ids, chunk)
-    # new nodes link only to LIVE candidates: tombstoned graph nodes are
-    # masked to -1/+inf (the top-k merge in _merge_candidates drops them)
-    cand_dead = state.dead[jnp.maximum(cand_ids, 0)] & (cand_ids >= 0)
-    cand_ids = jnp.where(cand_dead, -1, cand_ids)
-    cand_d = jnp.where(cand_dead, jnp.inf, cand_d)
-    pair_d = _pairwise_dists(cfg, vecs, pcs, chunk)
-
-    levels = jnp.asarray(levels, jnp.int32)
-    safe = jnp.where(admit, slots, cfg.capacity)     # OOB rows are dropped
-    state = state._replace(
-        vectors=state.vectors.at[safe].set(vecs, mode="drop"),
-        pb=state.pb.at[safe].set(pcs, mode="drop"),
-        node_level=state.node_level.at[safe].set(levels, mode="drop"),
-        dead=state.dead.at[safe].set(False, mode="drop"),
-        count=new_count)
-    fwd, sel = _merge_candidates(cfg, state, levels, admit, slots,
-                                 cand_ids, cand_d, pair_d)
-    state = _commit_batch(cfg, state, levels, admit, slots, fwd, sel)
+    with jax.named_scope("fold.insert.discover"):
+        cand_ids, cand_d = _discover_candidates(cfg, state, vecs, pcs,
+                                                levels, seed_ids, chunk)
+        # new nodes link only to LIVE candidates: tombstoned graph nodes are
+        # masked to -1/+inf (the top-k merge in _merge_candidates drops them)
+        cand_dead = state.dead[jnp.maximum(cand_ids, 0)] & (cand_ids >= 0)
+        cand_ids = jnp.where(cand_dead, -1, cand_ids)
+        cand_d = jnp.where(cand_dead, jnp.inf, cand_d)
+    with jax.named_scope("fold.insert.merge"):
+        pair_d = _pairwise_dists(cfg, vecs, pcs, chunk)
+        levels = jnp.asarray(levels, jnp.int32)
+        safe = jnp.where(admit, slots, cfg.capacity)  # OOB rows are dropped
+        state = state._replace(
+            vectors=state.vectors.at[safe].set(vecs, mode="drop"),
+            pb=state.pb.at[safe].set(pcs, mode="drop"),
+            node_level=state.node_level.at[safe].set(levels, mode="drop"),
+            dead=state.dead.at[safe].set(False, mode="drop"),
+            count=new_count)
+        fwd, sel = _merge_candidates(cfg, state, levels, admit, slots,
+                                     cand_ids, cand_d, pair_d)
+    with jax.named_scope("fold.insert.commit"):
+        state = _commit_batch(cfg, state, levels, admit, slots, fwd, sel)
     return state, n_ins
 
 

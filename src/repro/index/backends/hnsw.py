@@ -18,6 +18,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.programs import (ProgramBudget, ProgramSpec,
                                      register_programs)
@@ -117,9 +118,10 @@ class _HNSWLifecycle(DedupBackend):
         if self._known_count + self._dispatched_bound + fresh <= cap:
             self._dispatched_bound += fresh
             return
-        self._known_count = int(self.state.count)  # foldlint: sync-ok(rare re-anchor: only when the sync-free bound says the batch might not fit)
+        with TraceAnnotation("fold.sync.capacity"):
+            self._known_count = int(self.state.count)  # foldlint: sync-ok(rare re-anchor: only when the sync-free bound says the batch might not fit)
+            n_keep = int(np.asarray(keep).sum())  # foldlint: sync-ok(already syncing to re-anchor; exact kept count is free here)
         self._dispatched_bound = 0
-        n_keep = int(np.asarray(keep).sum())  # foldlint: sync-ok(already syncing to re-anchor; exact kept count is free here)
         fresh = max(0, n_keep - offered)
         if self._known_count + fresh > cap:
             raise RuntimeError(

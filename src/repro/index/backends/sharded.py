@@ -52,6 +52,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.programs import (ProgramBudget, ProgramSpec,
                                      register_programs)
@@ -172,7 +173,8 @@ class ShardedDedupBackend(DedupBackend):
         if self._known_max + self._bound + fresh <= cap:
             self._bound += fresh
             return
-        self._known_max = int(jnp.max(self.states.count))  # foldlint: sync-ok(rare re-anchor: only when the sync-free bound says the batch might not fit)
+        with TraceAnnotation("fold.sync.capacity"):
+            self._known_max = int(jnp.max(self.states.count))  # foldlint: sync-ok(rare re-anchor: only when the sync-free bound says the batch might not fit)
         self._bound = 0
         if self._known_max + fresh > cap:
             raise RuntimeError(

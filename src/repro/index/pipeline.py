@@ -25,6 +25,7 @@ from typing import TYPE_CHECKING, Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.index.protocol import (BATCH_FIRST, INDEX_FIRST, DedupBackend,
                                   SigBatch, StepResult)
@@ -203,8 +204,10 @@ class DedupPipeline:
         from repro.core.shingle import shingle_hashes
         from repro.kernels import ops
         spec = self._spec
-        sh = shingle_hashes(jnp.asarray(tokens, jnp.uint32),
-                            jnp.asarray(lengths, jnp.int32), spec.shingle_n)
+        with TraceAnnotation("fold.signatures.shingle"):
+            sh = shingle_hashes(jnp.asarray(tokens, jnp.uint32),
+                                jnp.asarray(lengths, jnp.int32),
+                                spec.shingle_n)
         sigs = bitmaps = pcs = None
         if self._seeds is not None:
             sigs = ops.minhash(sh, self._seeds, use_kernel=spec.use_kernel)
@@ -235,6 +238,10 @@ class DedupPipeline:
         Without it the step is dispatched as asynchronously as the backend
         allows, letting the executor overlap the next batch's signature
         stage with this step's device execution.
+
+        Host spans "fold.step.in_batch", "fold.step.search" and
+        "fold.step.insert" cover each stage's host work and dispatch (the
+        insert's includes the backend's level sampling and slot guard).
         """
         be = self.backend
         fused = getattr(be, "fused_step", None)
@@ -260,28 +267,31 @@ class DedupPipeline:
         block = timers is not None
 
         t0 = time.perf_counter()
-        keep_in_batch = greedy_leader(be.batch_sim(sig), be.tau_batch)
-        if block:
-            _ready(keep_in_batch)
-            timers["t_in_batch"] = time.perf_counter() - t0
+        with TraceAnnotation("fold.step.in_batch"):
+            keep_in_batch = greedy_leader(be.batch_sim(sig), be.tau_batch)
+            if block:
+                _ready(keep_in_batch)
+                timers["t_in_batch"] = time.perf_counter() - t0
 
         t0 = time.perf_counter()
-        ids, sims = be.search(sig)
-        dup_index = (sims >= be.tau_index).any(axis=-1)
-        if block:
-            _ready(dup_index)
-            timers["t_search"] = time.perf_counter() - t0
+        with TraceAnnotation("fold.step.search"):
+            ids, sims = be.search(sig)
+            dup_index = (sims >= be.tau_index).any(axis=-1)
+            if block:
+                _ready(dup_index)
+                timers["t_search"] = time.perf_counter() - t0
 
         keep = keep_in_batch & ~jnp.asarray(dup_index)
         if valid is not None:
             keep = keep & jnp.asarray(valid)
 
         t0 = time.perf_counter()
-        handle = self._insert(sig, keep, ids)
-        if block:
-            if handle is not None:   # device insert: charge it to t_insert
-                _ready(handle)
-            timers["t_insert"] = time.perf_counter() - t0
+        with TraceAnnotation("fold.step.insert"):
+            handle = self._insert(sig, keep, ids)
+            if block:
+                if handle is not None:   # device insert: charge t_insert
+                    _ready(handle)
+                timers["t_insert"] = time.perf_counter() - t0
         return StepResult(keep=keep, keep_in_batch=keep_in_batch,
                           ids=ids, sims=sims)
 

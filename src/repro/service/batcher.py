@@ -20,6 +20,7 @@ import time
 from typing import Iterable, NamedTuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["MicroBatch", "MicroBatcher", "Backpressure",
            "default_batch_buckets", "pow2_buckets"]
@@ -194,14 +195,15 @@ class MicroBatcher:
         B = _bucket_up(n, self.batch_buckets)
         L = _bucket_up(max((len(t) for _, t, _ in docs), default=1),
                        self.len_buckets)
-        tokens = np.zeros((B, L), np.uint32)
-        lengths = np.zeros((B,), np.int32)
-        valid = np.zeros((B,), bool)
-        doc_ids = np.full((B,), -1, np.int64)
-        for i, (did, t, _) in enumerate(docs):
-            tokens[i, : len(t)] = t
-            lengths[i] = len(t)
-            valid[i] = True
-            doc_ids[i] = did
+        with TraceAnnotation("fold.batch", B=B, L=L):
+            tokens = np.zeros((B, L), np.uint32)
+            lengths = np.zeros((B,), np.int32)
+            valid = np.zeros((B,), bool)
+            doc_ids = np.full((B,), -1, np.int64)
+            for i, (did, t, _) in enumerate(docs):
+                tokens[i, : len(t)] = t
+                lengths[i] = len(t)
+                valid[i] = True
+                doc_ids[i] = did
         self.emitted_shapes.add((B, L))
         return MicroBatch(tokens, lengths, valid, doc_ids, n)

@@ -22,6 +22,14 @@ Sequential-mode equivalence: the executor runs the exact same stage
 functions against the same evolving index state in the same order, so its
 keep-verdicts are bit-identical to a `process_batch` loop over the same
 micro-batches (tested in tests/test_service.py).
+
+Host spans (`jax.profiler.TraceAnnotation`, on the profiler's clock with
+the device's programs): "fold.dispatch.signatures" and "fold.dispatch.step"
+around the two dispatches, "fold.collect.wait" around the blocking
+materialization, "fold.sync.timers" around a timed batch's wait for the
+work before it; each carries the micro-batch's per-executor sequence
+number as `batch`. Without a profiler the same host times land in the
+`metrics` histograms "dispatch_ms" and "collect_wait_ms".
 """
 from __future__ import annotations
 
@@ -30,11 +38,14 @@ import dataclasses
 import time
 from typing import Any, Callable
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.index.pipeline import DedupPipeline
 from repro.index.protocol import StepResult
 from repro.service.batcher import MicroBatch
+from repro.service.metrics import MetricsRegistry
 
 __all__ = ["BatchOutcome", "PipelinedExecutor"]
 
@@ -49,6 +60,7 @@ class BatchOutcome:
     sims: np.ndarray           # (B, k) f32
     wall_s: float              # submit -> materialize (pipelined latency)
     stage_times: dict | None = None   # Fig. 7 per-stage seconds (sampled)
+    seq: int = -1              # the executor's sequence number of the batch
 
 
 class PipelinedExecutor:
@@ -64,21 +76,28 @@ class PipelinedExecutor:
     t_insert, or t_fused_step) lands in that batch's
     BatchOutcome.stage_times. A timed batch cannot overlap (the per-stage
     walls require blocking between stages), so this is sampled profiling:
-    one batch in every N pays the pipeline bubble. The very first batch is
-    never sampled — it pays XLA compilation (seconds), which would swamp
-    the latency histograms with one absurd sample.
+    one batch in every N pays the pipeline bubble, and first waits for the
+    work in flight, so that its stage times hold its own work alone. The
+    very first batch is never sampled — it pays XLA compilation (seconds),
+    which would swamp the latency histograms with one absurd sample.
+
+    metrics: optional registry for the host times of each batch —
+    "dispatch_ms" (both dispatches; timed batches, which block, are left
+    out) and "collect_wait_ms" (the blocking materialization).
     """
 
     def __init__(self, pipe: DedupPipeline, depth: int = 2,
                  on_outcome: Callable[[BatchOutcome], Any] | None = None,
-                 timers_every: int = 0):
+                 timers_every: int = 0,
+                 metrics: MetricsRegistry | None = None):
         self.pipe = pipe
         self.depth = max(int(depth), 0)
         self.on_outcome = on_outcome
         self.timers_every = max(int(timers_every), 0)
+        self.metrics = metrics
         self._submitted = 0
         self._inflight: collections.deque[tuple[MicroBatch, StepResult,
-                                                float, dict | None]] = \
+                                                float, dict | None, int]] = \
             collections.deque()
 
     @property
@@ -89,18 +108,30 @@ class PipelinedExecutor:
     def inflight_docs(self) -> int:
         """Valid docs dispatched but not yet materialized (backlog
         accounting for the bounded-admission check)."""
-        return sum(mb.n_docs for mb, _, _, _ in self._inflight)
+        return sum(entry[0].n_docs for entry in self._inflight)
 
     def submit(self, mb: MicroBatch) -> None:
         """Dispatch one micro-batch; may materialize older ones to keep the
         pipeline no more than `depth` deep."""
-        t0 = time.perf_counter()
-        timers = ({} if self.timers_every and self._submitted > 0
-                  and self._submitted % self.timers_every == 0 else None)
+        seq = self._submitted
+        timers = ({} if self.timers_every and seq > 0
+                  and seq % self.timers_every == 0 else None)
         self._submitted += 1
-        sig = self.pipe.signatures(mb.tokens, mb.lengths)
-        res = self.pipe.dedup_step(sig, valid=mb.valid, timers=timers)
-        self._inflight.append((mb, res, t0, timers))
+        if timers is not None:
+            # a timed batch times its own work alone: wait for the device
+            # work still in flight (not collecting it, so a failure there
+            # cannot take this batch down with it)
+            with TraceAnnotation("fold.sync.timers", batch=seq):
+                jax.block_until_ready([e[1] for e in self._inflight])  # foldlint: sync-ok(sampled timer mode blocks by design)
+        t0 = time.perf_counter()
+        with TraceAnnotation("fold.dispatch.signatures", batch=seq):
+            sig = self.pipe.signatures(mb.tokens, mb.lengths)
+        with TraceAnnotation("fold.dispatch.step", batch=seq):
+            res = self.pipe.dedup_step(sig, valid=mb.valid, timers=timers)
+        if self.metrics is not None and timers is None:
+            self.metrics.observe("dispatch_ms",
+                                 (time.perf_counter() - t0) * 1e3)
+        self._inflight.append((mb, res, t0, timers, seq))
         while len(self._inflight) > self.depth:
             self._collect_one()
 
@@ -110,20 +141,22 @@ class PipelinedExecutor:
             self._collect_one()
 
     def _collect_one(self) -> BatchOutcome:
-        mb, res, t0, timers = self._inflight.popleft()
+        mb, res, t0, timers, seq = self._inflight.popleft()
         # THE materialization point of the depth-k pipeline: by the time a
         # batch is collected here, its device work has had a full pipeline
         # depth to complete, so these blocks are overlap, not stalls
-        keep = np.asarray(res.keep)  # foldlint: sync-ok(pipeline materialization point: verdicts leave the device here by design)
-        out = BatchOutcome(
-            batch=mb,
-            keep=keep,
-            keep_in_batch=np.asarray(res.keep_in_batch),  # foldlint: sync-ok(pipeline materialization point)
-            ids=np.asarray(res.ids),  # foldlint: sync-ok(pipeline materialization point)
-            sims=np.asarray(res.sims),  # foldlint: sync-ok(pipeline materialization point)
-            wall_s=time.perf_counter() - t0,
-            stage_times=timers,
-        )
+        tw = time.perf_counter()
+        with TraceAnnotation("fold.collect.wait", batch=seq):
+            keep = np.asarray(res.keep)  # foldlint: sync-ok(pipeline materialization point: verdicts leave the device here by design)
+            keep_in_batch = np.asarray(res.keep_in_batch)  # foldlint: sync-ok(pipeline materialization point)
+            ids = np.asarray(res.ids)  # foldlint: sync-ok(pipeline materialization point)
+            sims = np.asarray(res.sims)  # foldlint: sync-ok(pipeline materialization point)
+        now = time.perf_counter()
+        if self.metrics is not None:
+            self.metrics.observe("collect_wait_ms", (now - tw) * 1e3)
+        out = BatchOutcome(batch=mb, keep=keep, keep_in_batch=keep_in_batch,
+                           ids=ids, sims=sims, wall_s=now - t0,
+                           stage_times=timers, seq=seq)
         if self.on_outcome is not None:
             self.on_outcome(out)
         return out

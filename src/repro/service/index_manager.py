@@ -30,6 +30,8 @@ from __future__ import annotations
 import os
 import shutil
 
+from jax.profiler import TraceAnnotation
+
 from repro.index.backends.sharded import ShardedDedupBackend  # noqa: F401
 from repro.index.pipeline import DedupPipeline
 from repro.train import checkpoint as ckpt
@@ -87,7 +89,8 @@ class IndexManager:
             return False
         # host sync: waits for every dispatched insert, so the true count
         # covers everything except the incoming batch
-        self._known_count = self.pipe.inserted
+        with TraceAnnotation("fold.sync.occupancy"):
+            self._known_count = self.pipe.inserted
         self._dispatched = 0
         if self._known_count + incoming < mark():
             return False
@@ -100,7 +103,8 @@ class IndexManager:
             new_cap = min(new_cap, self.max_capacity)
         grew = new_cap > self.pipe.capacity
         if grew:
-            self.pipe.grow(new_cap)
+            with TraceAnnotation("fold.grow", capacity=new_cap):
+                self.pipe.grow(new_cap)
             self.grow_events += 1
         # max_capacity may have clamped growth below what the batch needs
         # (or forbidden it entirely). Refuse rather than let the insert
@@ -123,7 +127,8 @@ class IndexManager:
         self._batches += 1
         if (self.snapshot_dir and self.snapshot_every
                 and self._batches % self.snapshot_every == 0):
-            self.snapshot(sync=False)
+            with TraceAnnotation("fold.snapshot"):
+                self.snapshot(sync=False)
 
     def snapshot(self, sync: bool = True) -> int:
         assert self.snapshot_dir, "no snapshot_dir configured"

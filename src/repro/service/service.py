@@ -26,9 +26,11 @@ executor still overlaps host signature prep with device search/insert.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import NamedTuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.dedup import FoldConfig
 from repro.core.hnsw import program_cache_sizes
@@ -186,7 +188,7 @@ class DedupService:
         self.executor = PipelinedExecutor(
             self.pipeline, depth=cfg.pipeline_depth,
             on_outcome=self._record_outcome,
-            timers_every=cfg.stage_timer_every)
+            timers_every=cfg.stage_timer_every, metrics=self.metrics)
         self._next_id = 0
         self._verdicts: dict[int, DocVerdict] = {}
         # exact front door: content hash of each queued (not yet
@@ -238,6 +240,10 @@ class DedupService:
             seq = [docs[i, : int(lengths[i])] for i in range(docs.shape[0])]
         else:
             seq = [np.asarray(d) for d in docs]
+        with TraceAnnotation("fold.submit", docs=len(seq)):
+            return self._enqueue(seq)
+
+    def _enqueue(self, seq: list) -> Ticket:
         n = len(seq)
         if self.cfg.max_pending_docs is not None \
                 and self.backlog() + n > self.cfg.max_pending_docs:
@@ -312,6 +318,14 @@ class DedupService:
 
     # ------------------------------------------------------------ results
     def _record_outcome(self, out: BatchOutcome) -> None:
+        t0 = time.perf_counter()
+        with TraceAnnotation("fold.record", batch=out.seq):
+            self._record_verdicts(out)
+        self.metrics.observe("record_ms", (time.perf_counter() - t0) * 1e3)
+        for hook in self.outcome_hooks:
+            hook(out)
+
+    def _record_verdicts(self, out: BatchOutcome) -> None:
         mb = out.batch
         self.metrics.observe("batch_ms", out.wall_s * 1e3)
         if out.stage_times:      # sampled Fig. 7 breakdown (stage_timer_every)
@@ -354,8 +368,6 @@ class DedupService:
             n = self.lifecycle.after_batch()
             if n:
                 self.metrics.inc("docs_deleted", n)
-        for hook in self.outcome_hooks:
-            hook(out)
 
     def verdict_ready(self, doc_id: int) -> bool:
         """True iff the doc's verdict is already in the store (requires
