@@ -36,11 +36,11 @@ BATCHED COMMIT by default: phase A discovers every kept row's per-level
 candidates in one chunked vmapped beam-search program against the
 pre-batch graph (optionally seeded from the admission step's own search
 results — `seed_ids`), and phase B commits the strictly order-dependent
-surgery (slot writes, adjacency rows, back-links, entry/top) in a compact
-branch-free lax.scan, with intra-batch links supplied by merging the
-batch's earlier rows into each candidate set. `HNSWConfig.batched_insert=
-False` keeps the historical per-doc traversal loop; a single-row batch is
-bit-identical between the two organizations.
+surgery (slot writes, adjacency rows, back-links, entry/top) with one loop
+per level over only the rows that link there, with intra-batch links
+supplied by merging the batch's earlier rows into each candidate set.
+`HNSWConfig.batched_insert=False` keeps the historical per-doc traversal
+loop; a single-row batch is bit-identical between the two organizations.
 
 The per-hop hot loop — distances from the query to the gathered neighbor
 rows — is exactly the bitmap-Jaccard XOR+popcount computation that
@@ -108,9 +108,10 @@ class HNSWConfig(NamedTuple):
     # phase A discovers every kept row's per-level candidates in ONE chunked
     # vmapped beam-search program against the pre-batch graph (optionally
     # seeded from the admission step's search results), phase B commits the
-    # cheap order-dependent graph surgery in a compact lax.scan. False = the
-    # historical per-doc fori_loop (one full top-down traversal per row),
-    # kept for the equivalence tests and as the conservative fallback.
+    # cheap order-dependent graph surgery, looping only over the (row,
+    # level) pairs that link. False = the historical per-doc fori_loop (one
+    # full top-down traversal per row), kept for the equivalence tests and
+    # as the conservative fallback.
     batched_insert: bool = True
 
     @property
@@ -732,9 +733,9 @@ def _merge_candidates(cfg: HNSWConfig, state: HNSWState, levels, admit,
                         (exactly _prune_row's selection, heuristic included)
       sel (B, L+1, M0)  the back-link targets (closest m_l, -1 padded)
 
-    Neither depends on the scan-time graph state — selection reads only
+    Neither depends on the commit-time graph state — selection reads only
     vectors (already slot-written) — so all of it runs as one vectorized
-    program, leaving only back-links and entry/top updates to the scan.
+    program, leaving only back-links and entry/top updates to the commit.
     `state` must be the slot-written state (batch vectors visible)."""
     B = slots.shape[0]
     E = cand_ids.shape[-1]
@@ -775,37 +776,62 @@ def _merge_candidates(cfg: HNSWConfig, state: HNSWState, levels, admit,
 
 def _commit_batch(cfg: HNSWConfig, state: HNSWState, levels, admit, slots,
                   fwd, sel) -> HNSWState:
-    """Phase B: the cheap, strictly order-dependent graph surgery as one
-    lax.scan — per admitted row: write the precomputed adjacency row,
-    back-link into the selected neighbors (_link_back), update entry/top.
+    """Phase B: the cheap, strictly order-dependent graph surgery — per
+    admitted row and level it links at: write the precomputed adjacency
+    row, back-link into the selected neighbors (_link_back); then entry/top.
     No graph traversals and no candidate selection happen here.
 
-    The body is deliberately BRANCH-FREE: a lax.cond over the carried state
-    would make XLA materialize both branch outputs (copies of the dense
-    neighbor arrays, every step); instead every write is a masked
-    scatter-with-drop, so skipped rows / inactive levels are no-ops on the
-    same in-place buffers. The sequential "first node" case needs no
-    special branch either: a first row has no candidates (every write
-    masks out) and the shared entry/top rule — entry moves when
-    level > running top — covers it (running top starts at -1)."""
-    def body(st, xs):
-        slot, adm, level, f_row, s_row = xs
-        top = st.top_level               # frozen for this row's insert
-        for lev in range(cfg.max_level, -1, -1):   # static unroll
-            m_l = cfg.M0 if lev == 0 else cfg.M
-            active = adm & (lev <= jnp.minimum(level, top))
-            slot_w = jnp.where(active, slot, cfg.capacity)   # OOB -> no-op
-            st = st._replace(neighbors=st.neighbors
-                             .at[lev, slot_w].set(f_row[lev], mode="drop"))
-            st = _link_back(cfg, st, slot, lev,
-                            jnp.where(active, s_row[lev, :m_l], -1), m_l)
-        higher = adm & (level > top)
-        return st._replace(
-            entry=jnp.where(higher, slot, st.entry),
-            top_level=jnp.where(adm, jnp.maximum(top, level), top)), None
+    Only the (row, level) pairs that link are run. Row r links at level l
+    iff it is admitted and l <= min(level[r], top_before[r]), where
+    top_before[r] is the running top it sees: the pre-batch top raised by
+    the levels of the admitted rows before it (an exclusive cumulative
+    max), so every pair's activity, and the final entry and top, are known
+    before any write. Levels do not interact here — a pass at level l
+    reads and writes only neighbors[l] (vectors and pb are already
+    slot-written) — so only the order of rows within a level matters. One
+    loop runs level 0's active rows in ascending row order, a second the
+    upper levels' active (level, row) pairs, level by level and rows
+    ascending within each: the same order as the sequential inserts. Each
+    loop's width m_l is static; the upper levels share one loop (their
+    level is read from the pair), which keeps the insert program within
+    its while-loop budget of 12 (a loop per level would compile 14).
 
-    xs = (slots, admit, levels, fwd, sel)
-    state, _ = jax.lax.scan(body, state, xs)
+    The loops are while loops with a dynamic trip count, carrying the state
+    in place: a lax.cond over the carried state would make XLA materialize
+    both branch outputs (copies of the dense neighbor arrays, every step).
+    The sequential "first node" case needs no special branch: a row that
+    sees a running top of -1 links at no level, and the entry/top rule —
+    entry moves to the last row whose level exceeds its running top —
+    covers it."""
+    B = slots.shape[0]
+    rows = jnp.arange(B, dtype=jnp.int32)
+    lvl = jnp.where(admit, levels, -1)
+    top_before = jnp.maximum(state.top_level, jnp.concatenate(
+        [jnp.full((1,), -1, jnp.int32), jax.lax.cummax(lvl)[:-1]]))
+    reach = jnp.where(admit, jnp.minimum(levels, top_before), -1)
+    raised = jnp.max(jnp.where(lvl > top_before, rows, -1))
+    state = state._replace(
+        entry=jnp.where(raised >= 0, slots[jnp.maximum(raised, 0)],
+                        state.entry),
+        top_level=jnp.maximum(state.top_level, jnp.max(lvl)))
+
+    def link(st, lo: int, n_lev: int, m_l: int):
+        """Run the active pairs of levels lo..lo+n_lev-1 at width m_l."""
+        act = reach[None, :] >= lo + jnp.arange(n_lev, dtype=jnp.int32)[:, None]
+        pairs = jnp.nonzero(act.reshape(-1), size=act.size, fill_value=0)[0]
+
+        def body(i, st):
+            r = pairs[i] % B
+            lev = lo if n_lev == 1 else lo + pairs[i] // B   # static at 0
+            st = st._replace(
+                neighbors=st.neighbors.at[lev, slots[r]].set(fwd[r, lev]))
+            return _link_back(cfg, st, slots[r], lev, sel[r, lev, :m_l], m_l)
+
+        return jax.lax.fori_loop(0, jnp.sum(act, dtype=jnp.int32), body, st)
+
+    state = link(state, 0, 1, cfg.M0)
+    if cfg.max_level > 0:
+        state = link(state, 1, cfg.max_level, cfg.M)
     return state
 
 
@@ -835,8 +861,8 @@ def hnsw_insert_batch(cfg: HNSWConfig, state: HNSWState, vecs: jnp.ndarray,
     Two organizations, selected by `cfg.batched_insert` (see HNSWConfig):
     the default two-phase batched commit discovers candidates for ALL rows
     in one chunked vmapped program against the pre-batch graph and then
-    scans over rows doing only slot/adjacency writes; the per-doc path runs
-    one full traversal per row inside a fori_loop. Both assign the same
+    loops over the linking rows doing only adjacency writes; the per-doc
+    path runs one full traversal per row inside a fori_loop. Both assign the same
     slots to the same rows; a single-row batch is bit-identical between
     them (phase A degenerates to the sequential search).
 

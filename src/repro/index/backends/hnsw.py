@@ -55,6 +55,9 @@ class _HNSWLifecycle(DedupBackend):
     hnsw_cfg: HNSWConfig
     state: HNSWState
     _batches: int
+    # host copy of the levels sampled for the latest insert (the serving
+    # executor pairs it with its batch for the commit_links histogram)
+    last_levels: np.ndarray | None = None
 
     # sync-free occupancy upper bound (mirrors ShardedDedupBackend): the
     # true count is a device scalar, so we only pay a host sync when the
@@ -144,6 +147,14 @@ class _HNSWLifecycle(DedupBackend):
                 or not getattr(self.cfg, "reuse_search", True)):
             return None
         return jnp.asarray(search_ids, jnp.int32)
+
+    # -- levels --------------------------------------------------------------
+    def _sample_levels(self, B: int) -> jnp.ndarray:
+        """The next batch's pre-sampled levels, kept on the host too."""
+        self.last_levels = sample_levels(
+            B, self.hnsw_cfg, seed=self._batches + self.cfg.seed + 1)
+        self._batches += 1
+        return jnp.asarray(self.last_levels)
 
     # -- occupancy -----------------------------------------------------------
     @property
@@ -412,9 +423,7 @@ class HNSWBitmapBackend(_HNSWLifecycle):
 
     def insert(self, sig: SigBatch, keep, search_ids=None):
         B = sig.bitmaps.shape[0]
-        levels = jnp.asarray(sample_levels(
-            B, self.hnsw_cfg, seed=self._batches + self.cfg.seed + 1))
-        self._batches += 1
+        levels = self._sample_levels(B)
         # refuse BEFORE any state mutation: once past the guard, every keep
         # row is guaranteed a slot, so the sig-store scatter below stays in
         # lockstep with the device insert (no desync on partial inserts)
@@ -507,9 +516,7 @@ class RawHNSWBackend(_HNSWLifecycle):
 
     def insert(self, sig: SigBatch, keep, search_ids=None):
         B = sig.sigs.shape[0]
-        levels = jnp.asarray(sample_levels(
-            B, self.hnsw_cfg, seed=self._batches + self.cfg.seed + 1))
-        self._batches += 1
+        levels = self._sample_levels(B)
         free_dev, free_host = self._prepare_slots(keep, B)
         self._record_insert(sig, keep, free_host)
         pcs = jnp.zeros(B, jnp.int32)          # unused by raw metrics

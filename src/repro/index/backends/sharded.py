@@ -84,6 +84,9 @@ class ShardedDedupBackend(DedupBackend):
     supports_snapshots = True
     supports_deletion = True
     track_slots = False
+    # host copy of the levels sampled for the latest step (the serving
+    # executor pairs it with its batch for the commit_links histogram)
+    last_levels: np.ndarray | None = None
 
     def __init__(self, cfg: FoldConfig, shards: int | None = None,
                  mesh=None, axis: str = "data"):
@@ -230,8 +233,10 @@ class ShardedDedupBackend(DedupBackend):
             bitmaps = jnp.pad(bitmaps, ((0, pad), (0, 0)))
             pcs = jnp.pad(pcs, (0, pad))
             valid = np.pad(np.asarray(valid), (0, pad))  # foldlint: sync-ok(valid is host numpy by contract; pad before device upload)
-        levels = jnp.asarray(sample_levels(
-            B + pad, self.hnsw_cfg, seed=self._batches + self.cfg.seed + 1))
+        lv = sample_levels(B + pad, self.hnsw_cfg,
+                           seed=self._batches + self.cfg.seed + 1)
+        self.last_levels = lv[:B]
+        levels = jnp.asarray(lv)
         self._batches += 1
         if self.track_slots and self._count_hw is None:
             # one-time sync of the per-shard high-water mirror, BEFORE the

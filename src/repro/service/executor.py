@@ -61,6 +61,7 @@ class BatchOutcome:
     wall_s: float              # submit -> materialize (pipelined latency)
     stage_times: dict | None = None   # Fig. 7 per-stage seconds (sampled)
     seq: int = -1              # the executor's sequence number of the batch
+    levels: np.ndarray | None = None  # (B,) HNSW levels its insert sampled
 
 
 class PipelinedExecutor:
@@ -96,9 +97,9 @@ class PipelinedExecutor:
         self.timers_every = max(int(timers_every), 0)
         self.metrics = metrics
         self._submitted = 0
-        self._inflight: collections.deque[tuple[MicroBatch, StepResult,
-                                                float, dict | None, int]] = \
-            collections.deque()
+        self._inflight: collections.deque[
+            tuple[MicroBatch, StepResult, float, dict | None, int,
+                  np.ndarray | None]] = collections.deque()
 
     @property
     def inflight(self) -> int:
@@ -128,10 +129,12 @@ class PipelinedExecutor:
             sig = self.pipe.signatures(mb.tokens, mb.lengths)
         with TraceAnnotation("fold.dispatch.step", batch=seq):
             res = self.pipe.dedup_step(sig, valid=mb.valid, timers=timers)
+        # the HNSW backends keep the levels they sampled on the host
+        levels = getattr(self.pipe.backend, "last_levels", None)
         if self.metrics is not None and timers is None:
             self.metrics.observe("dispatch_ms",
                                  (time.perf_counter() - t0) * 1e3)
-        self._inflight.append((mb, res, t0, timers, seq))
+        self._inflight.append((mb, res, t0, timers, seq, levels))
         while len(self._inflight) > self.depth:
             self._collect_one()
 
@@ -141,7 +144,7 @@ class PipelinedExecutor:
             self._collect_one()
 
     def _collect_one(self) -> BatchOutcome:
-        mb, res, t0, timers, seq = self._inflight.popleft()
+        mb, res, t0, timers, seq, levels = self._inflight.popleft()
         # THE materialization point of the depth-k pipeline: by the time a
         # batch is collected here, its device work has had a full pipeline
         # depth to complete, so these blocks are overlap, not stalls
@@ -156,7 +159,7 @@ class PipelinedExecutor:
             self.metrics.observe("collect_wait_ms", (now - tw) * 1e3)
         out = BatchOutcome(batch=mb, keep=keep, keep_in_batch=keep_in_batch,
                            ids=ids, sims=sims, wall_s=now - t0,
-                           stage_times=timers, seq=seq)
+                           stage_times=timers, seq=seq, levels=levels)
         if self.on_outcome is not None:
             self.on_outcome(out)
         return out

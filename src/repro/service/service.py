@@ -332,6 +332,13 @@ class DedupService:
             for key, secs in out.stage_times.items():
                 self.metrics.observe(f"{key}_ms", secs * 1e3)
         self.metrics.inc("docs_out", mb.n_docs)
+        if out.levels is not None:
+            # (row, level) pairs the insert's commit linked: levels 0..level
+            # of each kept row, against (max_level + 1) x B pairs a scan over
+            # every row would run; an upper bound that is off only for a row
+            # above the running top it saw, which links up to that top only
+            self.metrics.observe("commit_links",
+                                 float(np.sum(out.levels[out.keep] + 1)))
         best = out.sims.argmax(axis=-1)
         rows = np.arange(len(best))
         nbr_ids = out.ids[rows, best]

@@ -1,13 +1,16 @@
 """HNSW correctness: recall vs brute force, the paper's self-search
 diagnostic, structural invariants, and the batched-insert equivalence
 sweep (two-phase commit vs the per-doc path)."""
+import types
+
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 from repro.core.bitmap import pack_bitmaps, popcount, pairwise_bitmap_jaccard
-from repro.core.hnsw import (HNSWConfig, hnsw_init, hnsw_insert_batch,
-                             hnsw_search, sample_levels)
+from repro.core.hnsw import (HNSWConfig, hnsw_compact, hnsw_delete, hnsw_init,
+                             hnsw_insert_batch, hnsw_search, sample_levels)
 from repro.core.hnsw import _link_back
 
 RNG = np.random.default_rng(3)
@@ -348,3 +351,106 @@ def test_link_back_honors_select_heuristic():
     row_r = np.asarray(_link_back(cfg, state_room, jnp.int32(2), 0, sel,
                                   2).neighbors[0, 0])
     assert set(row_r.tolist()) == {1, 2}, row_r
+
+
+# ------------------------------------------- commit: linking pairs only
+def _commit_reference(cfg, state, levels, admit, slots, fwd, sel):
+    """The commit as a branch-free lax.scan over every row and every level:
+    an inactive (row, level) pair runs _link_back in full and has only its
+    writes dropped."""
+    def body(st, xs):
+        slot, adm, level, f_row, s_row = xs
+        top = st.top_level               # frozen for this row's insert
+        for lev in range(cfg.max_level, -1, -1):   # static unroll
+            m_l = cfg.M0 if lev == 0 else cfg.M
+            active = adm & (lev <= jnp.minimum(level, top))
+            slot_w = jnp.where(active, slot, cfg.capacity)   # OOB -> no-op
+            st = st._replace(neighbors=st.neighbors
+                             .at[lev, slot_w].set(f_row[lev], mode="drop"))
+            st = _link_back(cfg, st, slot, lev,
+                            jnp.where(active, s_row[lev, :m_l], -1), m_l)
+        higher = adm & (level > top)
+        return st._replace(
+            entry=jnp.where(higher, slot, st.entry),
+            top_level=jnp.where(adm, jnp.maximum(top, level), top)), None
+
+    state, _ = jax.lax.scan(body, state, (slots, admit, levels, fwd, sel))
+    return state
+
+
+def _insert_with_commit(commit):
+    """hnsw_insert_batch as a program of its own whose phase B is `commit`.
+    A new function object over patched globals, so that no trace of the
+    production program is shared with it."""
+    f = hnsw_insert_batch.__wrapped__
+    g = types.FunctionType(f.__code__,
+                           dict(f.__globals__, _commit_batch=commit),
+                           f.__name__, f.__defaults__, f.__closure__)
+    g.__kwdefaults__ = f.__kwdefaults__
+    return jax.jit(g, static_argnames=("cfg",))
+
+
+def _delete_and_compact(cfg, state, ids):
+    state, _ = hnsw_delete(cfg, state, ids)
+    state, _ = hnsw_compact(cfg, state)
+    return state
+
+
+@pytest.mark.parametrize("heuristic", [False, True])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_commit_links_only_active_pairs_bit_identical(seed, heuristic):
+    """The commit's per-level loops over the linking rows build the graph
+    the full 5-level scan over every row builds, bit for bit, on the same
+    forward rows and back-link targets: batches into an empty index (the
+    first row links nowhere), masked rows, rows at levels 1..3 and rows
+    that raise the top mid-batch, and slots reused from the free list."""
+    rng = np.random.default_rng(100 + seed)
+    B, n_batches = 32, 5
+    sigs = _corpus(B * n_batches, dup_rate=0.3)
+    vecs = pack_bitmaps(jnp.asarray(sigs), T=1024)
+    pcs = popcount(vecs)
+    cfg = HNSWConfig(capacity=256, words=vecs.shape[1], M=4, M0=8,
+                     ef_construction=16, ef_search=16, max_level=3,
+                     select_heuristic=heuristic)
+    ref_insert = _insert_with_commit(_commit_reference)
+
+    # levels grow batch by batch so that rows raise the top mid-batch
+    caps = [1, 2, 3, 3, 3]
+    st_new, st_ref = hnsw_init(cfg), hnsw_init(cfg)
+    raised = 0
+    for b in range(n_batches):
+        sl = slice(b * B, (b + 1) * B)
+        levels = np.where(rng.random(B) < 0.25,
+                          rng.integers(1, caps[b] + 1, B), 0).astype(np.int32)
+        levels[B // 2] = caps[b]
+        mask = rng.random(B) < 0.6
+        mask[B // 2] = True
+        free = None
+        if b == n_batches - 1:
+            # tombstone and compact a few nodes: the last batch drains them
+            ids = jnp.asarray(rng.choice(int(st_new.count), 6, replace=False),
+                              jnp.int32)
+            st_new = _delete_and_compact(cfg, st_new, ids)
+            st_ref = _delete_and_compact(cfg, st_ref, ids)
+            lv = np.asarray(st_new.node_level)[:int(st_new.count)]
+            free_ids = np.flatnonzero(lv < 0)
+            assert len(free_ids) >= 4
+            free = jnp.asarray(np.concatenate(
+                [free_ids, -np.ones(8 - len(free_ids) % 8, np.int64)]),
+                jnp.int32)
+        top0 = int(st_new.top_level)
+        raised += int(np.any(mask & (levels > top0)))
+        st_ref, n_ref = ref_insert(cfg, st_ref, vecs[sl], pcs[sl],
+                                   jnp.asarray(levels), jnp.asarray(mask),
+                                   free_slots=free)
+        st_new, n_new = hnsw_insert_batch(cfg, st_new, vecs[sl], pcs[sl],
+                                          jnp.asarray(levels),
+                                          jnp.asarray(mask), free_slots=free)
+        assert int(n_new) == int(n_ref) == int(mask.sum())
+        for name in ("neighbors", "entry", "top_level", "count",
+                     "node_level", "vectors"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(st_new, name)),
+                np.asarray(getattr(st_ref, name)), err_msg=f"{name} batch {b}")
+    assert raised >= 3
+    assert int(st_new.top_level) == 3

@@ -1,4 +1,5 @@
 """The online serving layer: micro-batching, pipelining, index lifecycle."""
+import dataclasses
 import os
 
 import numpy as np
@@ -402,3 +403,31 @@ def test_service_single_doc_requests():
     assert len({v.doc_id for v in verdicts}) == 12
     # 12 docs bucket up to B=16 with 4 masked padding rows
     assert svc.stats()["batching"]["compiled_shapes"] == [(16, 512)]
+
+
+@pytest.mark.parametrize("max_level", [0, 4])
+def test_service_counts_commit_links(max_level):
+    """stats() reports commit_links, one observation a batch: the (row,
+    level) pairs the insert's commit linked, levels 0..level of each kept
+    row under the levels the backend sampled. With every row at level 0 it
+    is the batch's kept count."""
+    fold = dataclasses.replace(FC, max_level=max_level)
+    svc = DedupService(ServiceConfig(
+        fold=fold, max_batch=64, max_wait_ms=0.0, batch_buckets=(64,),
+        stage_timer_every=0))
+    src = SyntheticCorpus(DATASET_PRESETS["common_crawl"])
+    toks, lens, _ = src.next_batch(192)
+    ticket = svc.submit(toks, lens)
+    kept = np.array([v.admitted for v in svc.results(ticket)]).reshape(3, 64)
+    hcfg = svc.pipeline.backend.hnsw_cfg
+    want = [int(np.sum(sample_levels(64, hcfg, seed=b + fold.seed + 1)
+                       [kept[b]] + 1)) for b in range(3)]
+    if max_level == 0:
+        assert want == kept.sum(axis=1).tolist()
+    else:
+        assert sum(want) > kept.sum()        # some kept row is above level 0
+    assert 0 < kept.sum() < kept.size
+    links = svc.stats()["latency_ms"]["commit_links"]
+    assert links["n"] == 3
+    assert links["max"] == max(want)
+    assert svc.metrics.histograms["commit_links"].sum == sum(want)
